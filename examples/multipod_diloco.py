@@ -23,6 +23,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P  # noqa: E402
 
 from repro.configs import get_config, reduced_config  # noqa: E402
 from repro.core.hlo_analysis import parse_collectives  # noqa: E402
+from repro.launch.mesh import make_mesh  # noqa: E402
 from repro.models import init_params  # noqa: E402
 from repro.parallel import diloco  # noqa: E402
 from repro.parallel.compression import wire_bytes  # noqa: E402
@@ -34,7 +35,7 @@ from repro.train import (  # noqa: E402
 
 def main():
     n_pods, h, rounds = 2, 4, 6
-    mesh = jax.make_mesh((n_pods, 2, 2), ("pod", "data", "model"))
+    mesh = make_mesh((n_pods, 2, 2), ("pod", "data", "model"))
     cfg = reduced_config(get_config("qwen1.5-0.5b"), d_model=64,
                          n_layers=2, vocab=512)
     params = init_params(jax.random.PRNGKey(0), cfg)

@@ -1,24 +1,13 @@
-"""Version shims for JAX APIs that moved between releases.
+"""``shard_map`` with the replication check off.
 
-``shard_map`` graduated from ``jax.experimental.shard_map`` to
-top-level ``jax.shard_map``, renaming ``check_rep`` to ``check_vma``
-along the way.  Every caller in this repo wants the replication check
-off (outputs deliberately mix replicated and sharded specs), so the
-shim bakes that in and callers pass only ``mesh``/``in_specs``/
-``out_specs``.
+Every caller in this repo wants ``check_vma=False`` (outputs deliberately
+mix replicated and sharded specs), so callers pass only
+``mesh``/``in_specs``/``out_specs``.
 """
 
 import jax
 
-if hasattr(jax, "shard_map"):
 
-    def shard_map(f, *, mesh, in_specs, out_specs):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=False)
-
-else:  # jax <= 0.4.x
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    def shard_map(f, *, mesh, in_specs, out_specs):
-        return _shard_map(f, mesh=mesh, in_specs=in_specs,
-                          out_specs=out_specs, check_rep=False)
+def shard_map(f, *, mesh, in_specs, out_specs):
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)

@@ -1,7 +1,8 @@
 """Core: the paper's contribution — tail-effect modeling and elimination."""
 
 from repro.core.hardware import (
-    HardwareSpec, TPU_V5E, TPU_V4, TPU_V5P, TPU_LITE, get_hardware,
+    HardwareSpec, TPU_V5E, TPU_V4, TPU_V5P, TPU_LITE, device_hardware,
+    get_hardware, hardware_for_kind,
 )
 from repro.core.tail_model import (
     LayerShape, StairPoint, StairTable, ModelStairTable,
@@ -24,7 +25,8 @@ from repro.core.hlo_analysis import (
 
 __all__ = [
     "HardwareSpec", "TPU_V5E", "TPU_V4", "TPU_V5P", "TPU_LITE",
-    "get_hardware", "LayerShape", "StairPoint", "StairTable",
+    "device_hardware", "get_hardware", "hardware_for_kind", "LayerShape",
+    "StairPoint", "StairTable",
     "ModelStairTable", "WaveQuantizationModel",
     "GridWaveModel", "staircase_edges", "ceil_div", "analytic_candidates",
     "profile_candidates", "model_profile_candidates",

@@ -95,6 +95,37 @@ REGISTRY: Dict[str, HardwareSpec] = {
 }
 
 
+# ``jax.Device.device_kind`` -> spec, for the device a run actually uses.
+DEVICE_KINDS: Dict[str, HardwareSpec] = {
+    "TPU v5 lite": TPU_V5E,
+    "TPU v5": TPU_V5P,
+    "TPU v4": TPU_V4,
+}
+
+
+def hardware_for_kind(kind: str) -> HardwareSpec:
+    """The spec of a TPU ``device_kind``; an unknown kind is an error,
+    never a default (its peaks would be guessed)."""
+    try:
+        return DEVICE_KINDS[kind]
+    except KeyError:
+        raise KeyError(
+            f"no HardwareSpec for device kind {kind!r}; known kinds: "
+            f"{sorted(DEVICE_KINDS)}") from None
+
+
+def device_hardware(device=None) -> "HardwareSpec | None":
+    """Spec of ``device`` (default ``jax.devices()[0]``): None off TPU,
+    where there are no chip peaks to report, and a KeyError for a TPU
+    kind missing from :data:`DEVICE_KINDS`."""
+    if device is None:
+        import jax
+        device = jax.devices()[0]
+    if device.platform != "tpu":
+        return None
+    return hardware_for_kind(device.device_kind)
+
+
 def get_hardware(name: str) -> HardwareSpec:
     try:
         return REGISTRY[name]

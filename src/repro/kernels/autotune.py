@@ -23,12 +23,17 @@ For each candidate block tuple the cost model computes
     latency_s = max(compute_s, memory_s)
     tail_free = every dim divides its block  AND  B % S == 0
 
-i.e. no padded tile lanes and no partial last wave.  Candidates that
-exceed the VMEM budget (operand blocks double-buffered + fp32
-accumulator + output block) are discarded.  Among survivors, tail-free
-configs are preferred when any exist; ties break by (latency_s,
-padded_flops, grid_blocks, blocks) — a pure function of (hardware,
-shape, dtype), so selection is deterministic per ``HardwareSpec``.
+i.e. no padded tile lanes and no partial last wave.  Candidates whose
+VMEM working set (operand and output blocks double-buffered + fp32
+scratch) exceeds the budget are discarded.  The budget is three quarters
+of the scoped VMEM the compiler grants one kernel by default
+(``KERNEL_VMEM_BYTES``, capped by ``hw.vmem_bytes``), not the chip's
+whole VMEM: the compiler keeps its own scratch in the same space, and on
+v5e it refused a bf16 (1024, 1024, 1024)-block matmul whose working set
+is 16 MiB.  Among survivors, tail-free configs are preferred when any
+exist; ties break by (latency_s, padded_flops, grid_blocks, blocks) — a
+pure function of (hardware, shape, dtype), so selection is deterministic
+per ``HardwareSpec``.
 
 Worked Eq. 3 example (TPU_LITE, S = cores_per_chip for the example's
 sake; take S = 4): a (512, 512, 512) matmul at the fixed default blocks
@@ -64,6 +69,9 @@ __all__ = [
 # lanes); the selection cost model prunes what VMEM can't hold.
 _M_EDGES = (8, 16, 32, 64, 128, 256, 512, 1024)
 _LANE_EDGES = (128, 256, 512, 1024)
+
+# Scoped VMEM the TPU compiler grants one Pallas kernel by default.
+KERNEL_VMEM_BYTES = 16 * 1024**2
 
 
 @dataclasses.dataclass(frozen=True)
@@ -103,6 +111,11 @@ def memo_stats() -> dict:
             "per_kernel": per_kernel}
 
 
+def _vmem_budget(hw: HardwareSpec) -> int:
+    """Bytes of VMEM a candidate tiling may hold (module docstring)."""
+    return min(hw.vmem_bytes, KERNEL_VMEM_BYTES) * 3 // 4
+
+
 def _select(cands: Sequence[TileConfig]) -> TileConfig:
     """Prefer tail-free tilings when any exist; break ties
     deterministically (latency, padded work, grid size, block tuple)."""
@@ -132,11 +145,9 @@ def _divisor_candidates(dim: int, edges: Sequence[int],
 
 def _matmul_config(hw: HardwareSpec, m: int, n: int, k: int,
                    bm: int, bn: int, bk: int,
-                   dtype_bits: int) -> Optional[TileConfig]:
+                   dtype_bits: int) -> TileConfig:
     bpe = dtype_bits // 8
-    vmem = 2 * (bm * bk + bk * bn) * bpe + bm * bn * (4 + bpe)
-    if vmem > hw.vmem_bytes:
-        return None
+    vmem = 2 * (bm * bk + bk * bn + bm * bn) * bpe + bm * bn * 4
     gm, gn, gk = ceil_div(m, bm), ceil_div(n, bn), ceil_div(k, bk)
     blocks = gm * gn * gk
     cell_flops = 2.0 * bm * bn * bk
@@ -163,25 +174,22 @@ def _matmul_candidates(hw: HardwareSpec, shape, dtype_bits: int):
         for bn in _edge_candidates(n, _LANE_EDGES):
             for bk in _edge_candidates(k, _LANE_EDGES):
                 cfg = _matmul_config(hw, m, n, k, bm, bn, bk, dtype_bits)
-                if cfg is not None:
+                if cfg.vmem_bytes <= _vmem_budget(hw):
                     out.append(cfg)
-    if not out:
-        out.append(_force_config(
-            _matmul_config, hw, (m, n, k),
-            (min(256, m), min(256, n), min(512, k)), dtype_bits))
+    if not out:     # degenerate spec: the clamped defaults regardless
+        out.append(_matmul_config(hw, m, n, k, min(256, m), min(256, n),
+                                  min(512, k), dtype_bits))
     return out
 
 
 def _flash_config(hw: HardwareSpec, b: int, sq: int, skv: int, h: int,
                   kv_heads: int, dh: int, bq: int, bkv: int,
-                  dtype_bits: int) -> Optional[TileConfig]:
+                  dtype_bits: int) -> TileConfig:
     bpe = dtype_bits // 8
-    # q block + double-buffered k/v blocks + fp32 scores, stats and
-    # accumulator scratch + output block.
-    vmem = (bq * dh * bpe + 2 * 2 * (bkv * dh) * bpe
-            + bq * bkv * 4 + bq * dh * 4 + 2 * bq * 4 + bq * dh * bpe)
-    if vmem > hw.vmem_bytes:
-        return None
+    # double-buffered q, k, v and output blocks + fp32 scores and
+    # accumulator + the two (bq, 1) stats, padded to 128 lanes.
+    vmem = (2 * (2 * bq + 2 * bkv) * dh * bpe
+            + bq * bkv * 4 + bq * dh * 4 + 2 * bq * 128 * 4)
     gq, gkv = ceil_div(sq, bq), ceil_div(skv, bkv)
     blocks = b * h * gq * gkv
     cell_flops = 4.0 * bq * bkv * dh
@@ -210,22 +218,19 @@ def _flash_candidates(hw: HardwareSpec, shape, dtype_bits: int):
                                        (128, 256, 512, 1024), cap=2048):
             cfg = _flash_config(hw, b, sq, skv, h, kv_heads, dh,
                                 bq, bkv, dtype_bits)
-            if cfg is not None:
+            if cfg.vmem_bytes <= _vmem_budget(hw):
                 out.append(cfg)
     if not out:
-        out.append(_force_config(
-            _flash_config, hw, (b, sq, skv, h, kv_heads, dh),
-            (min(512, sq), min(512, skv)), dtype_bits))
+        out.append(_flash_config(hw, b, sq, skv, h, kv_heads, dh,
+                                 min(512, sq), min(512, skv), dtype_bits))
     return out
 
 
 def _moe_config(hw: HardwareSpec, e: int, c: int, d: int, f: int,
                 bc: int, bf: int, bd: int,
-                dtype_bits: int) -> Optional[TileConfig]:
+                dtype_bits: int) -> TileConfig:
     bpe = dtype_bits // 8
-    vmem = 2 * (bc * bd + bd * bf) * bpe + bc * bf * (4 + bpe)
-    if vmem > hw.vmem_bytes:
-        return None
+    vmem = 2 * (bc * bd + bd * bf + bc * bf) * bpe + bc * bf * 4
     gc, gf, gd = ceil_div(c, bc), ceil_div(f, bf), ceil_div(d, bd)
     blocks = e * gc * gf * gd
     cell_flops = 2.0 * bc * bf * bd
@@ -250,20 +255,12 @@ def _moe_candidates(hw: HardwareSpec, shape, dtype_bits: int):
         for bf in _edge_candidates(f, _LANE_EDGES):
             for bd in _edge_candidates(d, _LANE_EDGES):
                 cfg = _moe_config(hw, e, c, d, f, bc, bf, bd, dtype_bits)
-                if cfg is not None:
+                if cfg.vmem_bytes <= _vmem_budget(hw):
                     out.append(cfg)
     if not out:
-        out.append(_force_config(
-            _moe_config, hw, (e, c, d, f),
-            (min(128, c), min(256, f), min(256, d)), dtype_bits))
+        out.append(_moe_config(hw, e, c, d, f, min(128, c), min(256, f),
+                               min(256, d), dtype_bits))
     return out
-
-
-def _force_config(config_fn, hw, shape, blocks, dtype_bits) -> TileConfig:
-    """Build the clamped-defaults config ignoring the VMEM filter — the
-    last resort when no candidate fits (degenerate HardwareSpecs)."""
-    big = dataclasses.replace(hw, vmem_bytes=1 << 62)
-    return config_fn(big, *shape, *blocks, dtype_bits)
 
 
 _KERNELS = {
@@ -301,7 +298,8 @@ def _score_blocks(kernel: str, hw: HardwareSpec, shape, blocks,
     fn = {"matmul": _matmul_config, "flash_attention": _flash_config,
           "moe_gmm": _moe_config}[kernel]
     cfg = fn(hw, *shape, *blocks, dtype_bits)
-    if cfg is None:   # persisted under a larger-VMEM spec: rebuild fresh
+    if cfg.vmem_bytes > _vmem_budget(hw):
+        # persisted under a larger budget: rebuild fresh
         return _select(_KERNELS[kernel](hw, shape, dtype_bits))
     return cfg
 

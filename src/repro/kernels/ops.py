@@ -74,10 +74,12 @@ def kernel_context(hw=None, cache=None, force: Optional[str] = None):
     """Install a :class:`KernelContext` for the duration of the block.
     Trace-time scoping: model code traced under this context bakes the
     context's tile choices into the jaxpr, so an AOT-compiled executable
-    keeps its autotuned blocks forever."""
+    keeps its autotuned blocks forever.  Fields left unset inherit from
+    an enclosing context, so an outer ``force=`` reaches the contexts
+    the serving compile cache installs."""
     global _KERNEL_CTX
     prev = _KERNEL_CTX
-    _KERNEL_CTX = KernelContext(hw=hw, cache=cache, force=force)
+    _KERNEL_CTX = KernelContext(*_ctx_fallback(hw, cache, force))
     try:
         yield _KERNEL_CTX
     finally:

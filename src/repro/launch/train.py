@@ -22,13 +22,29 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs import get_config, list_archs, reduced_config
+from repro.launch.jax_cache import enable_compile_cache
 from repro.launch.mesh import describe, make_host_mesh
 from repro.models import init_params
 from repro.parallel import sharding as shlib
+from repro.parallel.sharding import param_shardings
 from repro.train import (
     AdamWConfig, DataConfig, TrainConfig, adamw_init, build_train_step,
     checkpoint, cosine_schedule, make_source, augment_for_arch,
 )
+
+
+def train_config(microbatches: int = 1, remat: str = "none") -> TrainConfig:
+    return TrainConfig(adamw=AdamWConfig(), microbatches=microbatches,
+                       remat=remat, moe_strategy="dense")
+
+
+def init_train_state(cfg, tc: TrainConfig, mesh, seed: int):
+    """Params placed by ``param_shardings`` on ``mesh`` (FSDP over
+    ``data``, tensor parallel over ``model``) and their AdamW state; the
+    first step's outputs carry the moments' sharding from there."""
+    params = init_params(jax.random.PRNGKey(seed), cfg)
+    params = jax.device_put(params, param_shardings(params, mesh))
+    return params, adamw_init(params, tc.adamw)
 
 
 def main(argv=None):
@@ -58,11 +74,11 @@ def main(argv=None):
     if args.reduced:
         cfg = reduced_config(cfg, n_layers=args.n_layers,
                              d_model=args.d_model)
+    enable_compile_cache()
     mesh = make_host_mesh()
     print(f"mesh: {describe(mesh)}  arch: {cfg.name}")
 
-    tc = TrainConfig(adamw=AdamWConfig(), microbatches=args.microbatches,
-                     remat=args.remat, moe_strategy="dense")
+    tc = train_config(args.microbatches, args.remat)
     lr = cosine_schedule(args.lr, max(args.steps // 20, 1), args.steps)
     data_cfg = DataConfig(vocab_size=cfg.vocab_size, seq_len=args.seq,
                           global_batch=args.batch, seed=args.seed,
@@ -70,8 +86,7 @@ def main(argv=None):
     source = make_source(data_cfg)
 
     with shlib.activity(mesh, {}):
-        params = init_params(jax.random.PRNGKey(args.seed), cfg)
-        opt_state = adamw_init(params, tc.adamw)
+        params, opt_state = init_train_state(cfg, tc, mesh, args.seed)
         step_fn = jax.jit(build_train_step(cfg, tc, lr),
                           donate_argnums=(0, 1))
 

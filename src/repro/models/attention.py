@@ -127,9 +127,9 @@ def chunked_attention(
     qc, kc = _chunk_sizes(sq, skv, q_chunk, kv_chunk)
     nq, nk = sq // qc, skv // kc
 
-    qr = q.reshape(b, nq, qc, kv, g, dh).astype(COMPUTE_DTYPE)
-    kr = k.reshape(b, nk, kc, kv, dh).astype(COMPUTE_DTYPE)
-    vr = v.reshape(b, nk, kc, kv, dh).astype(COMPUTE_DTYPE)
+    qr = cast(q.reshape(b, nq, qc, kv, g, dh))
+    kr = cast(k.reshape(b, nk, kc, kv, dh))
+    vr = cast(v.reshape(b, nk, kc, kv, dh))
 
     q_pos_base = q_offset + jnp.arange(nq) * qc            # (nq,)
     k_pos_base = jnp.arange(nk) * kc                       # (nk,)
@@ -163,7 +163,7 @@ def chunked_attention(
             corr = jnp.exp(m - m_new)
             l_new = l * corr + jnp.sum(p, axis=-1)
             pv = jnp.einsum("bkgqc,bckd->bkgqd",
-                            p.astype(COMPUTE_DTYPE), vblk,
+                            cast(p), vblk,
                             preferred_element_type=jnp.float32)
             acc_new = acc * corr[..., None] + pv
             return (m_new, l_new, acc_new), None
@@ -182,7 +182,7 @@ def chunked_attention(
                            (qr.transpose(1, 0, 2, 3, 4, 5), q_pos_base))
     # outs: (nq, b, kv, g, qc, dh) -> (b, sq, h, dh)
     o = outs.transpose(1, 0, 4, 2, 3, 5).reshape(b, sq, h, dh)
-    return o.astype(COMPUTE_DTYPE)
+    return cast(o)
 
 
 def prefill_attention(q, k, v, *, mask_kind: str = "causal",

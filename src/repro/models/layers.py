@@ -7,6 +7,7 @@ with logical axes via ``repro.parallel.shard``.
 
 from __future__ import annotations
 
+import contextlib
 import math
 from typing import Optional
 
@@ -21,6 +22,19 @@ PARAM_DTYPE = jnp.float32
 
 def cast(x):
     return x.astype(COMPUTE_DTYPE)
+
+
+@contextlib.contextmanager
+def compute_dtype(dtype):
+    """Trace model code computing in ``dtype`` instead of bfloat16 for
+    the duration of the block (trace-time, like ``kernel_context``): the
+    float32 reference forward is the only caller."""
+    global COMPUTE_DTYPE
+    prev, COMPUTE_DTYPE = COMPUTE_DTYPE, dtype
+    try:
+        yield
+    finally:
+        COMPUTE_DTYPE = prev
 
 
 # ---------------------------------------------------------------------------

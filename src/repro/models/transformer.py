@@ -31,8 +31,8 @@ from repro.models import moe as moe_lib
 from repro.models import recurrent as rec_lib
 from repro.models.layers import (
     COMPUTE_DTYPE, PARAM_DTYPE, apply_mlp, apply_mrope, apply_norm,
-    apply_rope, cast, embed_tokens, init_embeddings, init_mlp, init_norm,
-    unembed,
+    apply_rope, cast, compute_dtype, embed_tokens, init_embeddings,
+    init_mlp, init_norm, unembed,
 )
 from repro.parallel.sharding import current_mesh, shard
 
@@ -592,6 +592,20 @@ def forward(params, cfg: ModelConfig, *, tokens=None, embeds=None,
                      cfg.logit_softcap)
     logits = _mask_vocab_pad(logits, cfg)
     return logits, new_states, aux
+
+
+def reference_logits(params, cfg: ModelConfig, tokens) -> jax.Array:
+    """The plain reference the serving path is checked against: the
+    uncached full forward over ``tokens`` computed in float32 at the
+    highest matmul precision (a TPU runs float32 matmuls in bfloat16
+    passes otherwise).  Call it outside any ``kernels.ops.kernel_context``
+    so no kernel is on its path."""
+    with compute_dtype(jnp.float32), jax.default_matmul_precision("highest"):
+        # a fresh jit per call: the compute dtype is trace-time state
+        # that jax's trace cache does not key on
+        logits, _, _ = jax.jit(
+            lambda p, t: forward(p, cfg, tokens=t))(params, tokens)
+    return logits
 
 
 def _mask_vocab_pad(logits, cfg: ModelConfig):

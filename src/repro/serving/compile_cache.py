@@ -14,11 +14,13 @@ staircase tables one:
     ``ContinuousServeEngine.warm_compile``), keyed on
     ``(hardware fingerprint, kind, realized plan key, shape bucket)``.
     A warm boundary crossing is then a dict lookup — never a trace.
-  * Serve-time entry points (:meth:`prefill` / :meth:`decode`) fall back
-    to an ordinary traced ``jax.jit`` path on any miss or fault, so a
-    cold or broken cache degrades to today's behavior, never to a lost
-    request.  ``serving.chaos.CompileFailureInjector`` exercises exactly
-    this contract through ``fault_hook``.
+  * Serve-time entry points (:meth:`prefill` / :meth:`decode` /
+    :meth:`chunk`) take an ordinary traced ``jax.jit`` path on a miss,
+    so a cold cache serves like the historical jit lambdas.  A fault
+    that ``fault_hook`` injects (``serving.chaos.CompileFailureInjector``)
+    takes the same traced path and is counted in ``stats["fallbacks"]``.
+    A real lowering, compile or execution error raises: the device is
+    never hidden behind a silent retrace.
   * :meth:`decide` is the **cost crossover**: when a plan's modeled
     saving over the engine's horizon is smaller than one AOT compile,
     the plan should be realized as *zero-masked full-shape params*
@@ -220,34 +222,43 @@ class WidthVariantCompileCache:
     # ------------------------------------------------------------------
     # plan-time AOT compilation
     # ------------------------------------------------------------------
-    def _check(self, step: str) -> None:
-        if self.fault_hook is not None:
+    def _injected(self, step: str, ek: tuple, t0: "float | None" = None
+                  ) -> bool:
+        """Run the fault hook at ``step``.  True when it raised: the
+        fault is recorded and the caller takes the traced path.  Only
+        the hook's own faults are absorbed this way."""
+        if self.fault_hook is None:
+            return False
+        try:
             self.fault_hook(step)
+        except Exception as e:  # noqa: BLE001 — an injected fault
+            self.stats["fallbacks"] += 1
+            self.events.append(CompileEvent(
+                kind=ek[1], key=ek, outcome="fault",
+                wall_s=0.0 if t0 is None else time.perf_counter() - t0,
+                error=f"{type(e).__name__}: {e}"))
+            return True
+        return False
 
     def precompile(self, kind: str, key: tuple, shape_key: tuple,
                    example_args: tuple) -> bool:
         """AOT-compile one (kind, realized key, shape) executable from
         example args (arrays or ShapeDtypeStructs).  Returns True when
-        the entry is warm afterwards; a compile fault is recorded and
-        absorbed (the serve path falls back to the traced jit)."""
+        the entry is warm afterwards, False when the fault hook refused
+        it (the serve path then traces).  A real lowering or compile
+        error raises: a kernel the compiler refuses must stop the run."""
         if kind not in self._jit:
             raise ValueError(f"unknown kind {kind!r}")
         ek = self._entry_key(kind, key, shape_key)
         if ek in self._exec:
             return True
         t0 = time.perf_counter()
-        try:
-            self._check("lower")
-            lowered = self._jit[kind].lower(*example_args)
-            self._check("compile")
-            compiled = lowered.compile()
-        except Exception as e:  # noqa: BLE001 — fault => traced fallback
-            self.stats["fallbacks"] += 1
-            self.events.append(CompileEvent(
-                kind=kind, key=ek, outcome="fault",
-                wall_s=time.perf_counter() - t0,
-                error=f"{type(e).__name__}: {e}"))
+        if self._injected("lower", ek, t0):
             return False
+        lowered = self._jit[kind].lower(*example_args)
+        if self._injected("compile", ek, t0):
+            return False
+        compiled = lowered.compile()
         self._exec[ek] = compiled
         while len(self._exec) > self.max_entries:
             self._exec.popitem(last=False)
@@ -257,20 +268,17 @@ class WidthVariantCompileCache:
             wall_s=time.perf_counter() - t0))
         return True
 
+    def executable(self, kind: str, key: tuple, shape_key: tuple):
+        """The warm AOT executable for an entry, or None."""
+        return self._exec.get(self._entry_key(kind, key, shape_key))
+
     # ------------------------------------------------------------------
     # serve-time entry points
     # ------------------------------------------------------------------
     def _get(self, kind: str, shape_key: tuple):
-        try:
-            self._check("lookup")
-        except Exception as e:  # noqa: BLE001 — fault => traced fallback
-            self.stats["fallbacks"] += 1
-            self.events.append(CompileEvent(
-                kind=kind,
-                key=self._entry_key(kind, self._active_key, shape_key),
-                outcome="fault", error=f"{type(e).__name__}: {e}"))
-            return None
         ek = self._entry_key(kind, self._active_key, shape_key)
+        if self._injected("lookup", ek):
+            return None
         exe = self._exec.get(ek)
         if exe is None:
             self.stats["misses"] += 1
@@ -281,43 +289,33 @@ class WidthVariantCompileCache:
         self.stats["hits"] += 1
         return exe
 
+    def _call(self, kind: str, shape_key: tuple, *args):
+        """AOT hit, else the traced jit (a miss, or a lookup fault the
+        hook injected).  Errors raised by the executable propagate."""
+        exe = self._get(kind, shape_key)
+        if exe is None:
+            return self._jit[kind](*args)
+        return exe(*args)
+
     def prefill(self, params, toks):
-        """AOT-hit prefill, else the traced fallback.  Same signature
-        and return value as the engines' historical jit lambda."""
-        shape_key = tuple(int(d) for d in toks.shape)
-        exe = self._get("prefill", shape_key)
-        if exe is not None:
-            try:
-                return exe(params, toks)
-            except Exception:  # noqa: BLE001 — shape/aval drift => fallback
-                self.stats["fallbacks"] += 1
-        return self._jit["prefill"](params, toks)
+        """Whole-prompt prefill.  Same signature and return value as the
+        engines' historical jit lambda."""
+        return self._call("prefill", tuple(int(d) for d in toks.shape),
+                          params, toks)
 
     def decode(self, params, toks, pos, states):
-        """AOT-hit decode step, else the traced fallback."""
-        shape_key = tuple(int(d) for d in toks.shape)
-        exe = self._get("decode", shape_key)
-        if exe is not None:
-            try:
-                return exe(params, toks, pos, states)
-            except Exception:  # noqa: BLE001 — shape/aval drift => fallback
-                self.stats["fallbacks"] += 1
-        return self._jit["decode"](params, toks, pos, states)
+        """One decode step."""
+        return self._call("decode", tuple(int(d) for d in toks.shape),
+                          params, toks, pos, states)
 
     def chunk(self, params, toks, pos, states):
-        """AOT-hit prefill chunk (``tfm.prefill_chunk``), else the traced
-        fallback.  The chunk offset ``pos`` is a traced argument, so one
-        executable per chunk *shape* serves every chunk position — the
-        chunked-prefill shape set is {(1, chunk)} plus the pow2 tail
-        buckets, bounded exactly like bucketed whole-prompt prefill."""
-        shape_key = tuple(int(d) for d in toks.shape)
-        exe = self._get("chunk", shape_key)
-        if exe is not None:
-            try:
-                return exe(params, toks, pos, states)
-            except Exception:  # noqa: BLE001 — shape/aval drift => fallback
-                self.stats["fallbacks"] += 1
-        return self._jit["chunk"](params, toks, pos, states)
+        """One prefill chunk (``tfm.prefill_chunk``).  The chunk offset
+        ``pos`` is a traced argument, so one executable per chunk *shape*
+        serves every chunk position — the chunked-prefill shape set is
+        {(1, chunk)} plus the pow2 tail buckets, bounded exactly like
+        bucketed whole-prompt prefill."""
+        return self._call("chunk", tuple(int(d) for d in toks.shape),
+                          params, toks, pos, states)
 
 
 def decode_state_struct(cfg: ModelConfig, b: int, max_len: int, *,

@@ -87,6 +87,7 @@ class BoundaryEvent:
     #                           "reshape_failed" | "requeued_grow"
     requeued: int             # in-flight requests sent back to the queue
     error: str = ""           # repr of the mid-boundary exception, if any
+    carried: int = 0          # in-flight requests whose KV crossed live
 
 
 @dataclasses.dataclass(frozen=True)
@@ -334,9 +335,12 @@ class ContinuousServeEngine:
         executable (and bucketed single-request prefill executables for
         ``prefill_lengths``) for every plan — plus the full-width
         baseline — so boundary crossings and joins are table lookups.
-        Masked-crossover plans warm the full-width key.  Returns the
-        number of executables warmed; compile faults are absorbed (the
-        serve path falls back to the traced jit)."""
+        With chunked prefill the chunk executables are warmed instead of
+        whole-prompt prefill: the full chunk plus the pow2 buckets of
+        each prompt's final partial chunk.  Masked-crossover plans warm
+        the full-width key.  Returns the number of executables warmed;
+        only faults the cache's hook injects are absorbed (the serve
+        path then traces)."""
         if self.compile_cache is None:
             return 0
         from repro.serving.compile_cache import (
@@ -389,6 +393,14 @@ class ContinuousServeEngine:
                 toks = jnp.zeros((1, plen), jnp.int32)
                 n += cache.precompile("prefill", key, (1, plen),
                                       (params, toks))
+            if chunk_buckets:
+                st1 = decode_state_struct(self.cfg, 1, self.max_len,
+                                          swapper=self.swapper, heads=heads)
+                off = jnp.zeros((), jnp.int32)
+                for clen in chunk_buckets:
+                    toks = jnp.zeros((1, clen), jnp.int32)
+                    n += cache.precompile("chunk", key, (1, clen),
+                                          (params, toks, off, st1))
             if plan is not None:
                 cache.mark_plan_warm(plan)
         cache.set_active(prev_key)
@@ -668,16 +680,18 @@ class ContinuousServeEngine:
                      np.asarray(tr.generated, dtype=np.int32)])
                 buf = np.zeros(padded, np.int32)
                 buf[:clen] = prompt[tr.prefill_done:tr.prefill_done + clen]
-                try:
-                    if self.chunk_fault_hook is not None:
+                if self.chunk_fault_hook is not None:
+                    try:
                         self.chunk_fault_hook()
-                    logits, tr.chunk_state = self._chunk(
-                        self.params_active, buf[None],
-                        jnp.asarray(tr.prefill_done, jnp.int32),
-                        tr.chunk_state)
-                except Exception as e:  # noqa: BLE001 — checkpoint restart
-                    self._chunk_fault(i, tr, e)
-                    continue
+                    except Exception as e:  # noqa: BLE001 — injected
+                        self._chunk_fault(i, tr, e)
+                        continue
+                # an error from the executable itself is not a chunk
+                # fault to restart from: it propagates
+                logits, tr.chunk_state = self._chunk(
+                    self.params_active, buf[None],
+                    jnp.asarray(tr.prefill_done, jnp.int32),
+                    tr.chunk_state)
                 tr.prefill_done += clen
                 spent += padded
                 self.chunk_steps += 1
@@ -704,7 +718,7 @@ class ContinuousServeEngine:
             self._release(i)
 
     def _chunk_fault(self, i: int, tr: _Tracked, e: Exception) -> None:
-        """A chunk execution faulted: free the slot and requeue the
+        """The chunk fault hook fired: free the slot and requeue the
         request *keeping its checkpoint* — recovery resumes from the last
         committed chunk, not token zero.  Past ``max_retries`` the
         request fails terminally (checkpoint dropped)."""
@@ -879,7 +893,8 @@ class ContinuousServeEngine:
         g = self.cfg.n_heads // max(self.cfg.n_kv_heads, 1)
         kv_from = np.maximum(self._heads_active // g, 1)
         kv_to = np.maximum(heads_to // g, 1)
-        live = any(tr is not None for tr in self._slots)
+        n_live = sum(tr is not None for tr in self._slots)
+        live = n_live > 0
         shape_to = self._full_heads.copy() if masked else heads_to
         if live and (kv_to > kv_from).any():
             # Growing KV heads cannot restore sliced-away history:
@@ -890,6 +905,7 @@ class ContinuousServeEngine:
             requeued = self._requeue_in_flight()
             self.states = self._fresh_states(shape_to)
             outcome = "requeued_grow"
+            n_live = 0
         elif masked and (shape_to == self._shape_heads).all():
             # Masked realization on already-canonical shapes: the
             # dropped heads are zero-weighted on both the q and output
@@ -932,7 +948,7 @@ class ContinuousServeEngine:
         self.plan_log.append(plan)
         self.boundary_log.append(BoundaryEvent(
             step=self.steps, plan_name=plan.traffic.name,
-            outcome=outcome, requeued=requeued))
+            outcome=outcome, requeued=requeued, carried=n_live))
 
     # ------------------------------------------------------------------
     # the engine step

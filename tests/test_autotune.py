@@ -36,9 +36,9 @@ def _fresh_memo():
 def _matmul_default_config(hw, m, n, k):
     """Score the historical fixed (256, 256, 512) default through the
     same cost model the autotuner uses."""
-    from repro.kernels.autotune import _force_config, _matmul_config
-    return _force_config(_matmul_config, hw, (m, n, k),
-                         (min(256, m), min(256, n), min(512, k)), 16)
+    from repro.kernels.autotune import _matmul_config
+    return _matmul_config(hw, m, n, k, min(256, m), min(256, n),
+                          min(512, k), 16)
 
 
 class TestDeterminism:

@@ -189,6 +189,33 @@ class TestExecutables:
         assert cache.stats["fallbacks"] == 1
         assert cache.stats["hits"] == 0
 
+    def test_real_compile_error_raises(self, setup):
+        """A lowering the hook did not inject fails the warm-up instead
+        of being absorbed into a serve-time retrace."""
+        cfg, params = setup
+        cache = WidthVariantCompileCache(cfg)
+        bad = jnp.zeros((1, 8), jnp.float32)        # tokens must be ints
+        with pytest.raises(Exception):
+            cache.precompile("prefill", cache.full_key, (1, 8),
+                             (params, bad))
+        assert cache.stats["fallbacks"] == 0 and len(cache) == 0
+        assert not any(e.outcome == "fault" for e in cache.events)
+
+    def test_executable_error_raises_not_retraced(self, setup):
+        """An AOT executable fed arguments it was not compiled for raises;
+        the traced jit does not silently take over."""
+        cfg, params = setup
+        cache = WidthVariantCompileCache(cfg)
+        toks = jnp.zeros((1, 8), jnp.int32)
+        assert cache.precompile("prefill", cache.full_key, (1, 8),
+                                (params, toks))
+        traced = cache.tracer.count
+        wrong = jax.tree.map(lambda x: x.astype(jnp.bfloat16), params)
+        with pytest.raises(Exception):
+            cache.prefill(wrong, toks)
+        assert cache.tracer.count == traced
+        assert cache.stats["fallbacks"] == 0
+
     def test_lru_bounds_executables(self, setup):
         cfg, params = setup
         cache = WidthVariantCompileCache(cfg, max_entries=1)

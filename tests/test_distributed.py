@@ -19,6 +19,7 @@ os.environ["JAX_PLATFORMS"] = "cpu"
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import PartitionSpec as P
+from repro.launch.mesh import make_mesh
 """
 
 
@@ -37,7 +38,7 @@ class TestFlashDecodeSharded:
         run_sub("""
         from repro.models.attention import flash_decode_sharded, \\
             decode_attention, update_cache_sharded
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = make_mesh((2, 4), ("data", "model"))
         b, s, h, kv, dh = 4, 64, 8, 2, 16
         q = jax.random.normal(jax.random.PRNGKey(0), (b, h, dh))
         k = jax.random.normal(jax.random.PRNGKey(1), (b, s, kv, dh))
@@ -65,7 +66,7 @@ class TestMoeEP:
         run_sub("""
         from repro.models import moe as moe_lib
         from repro.parallel import sharding as shlib
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = make_mesh((2, 4), ("data", "model"))
         d, e, f, k = 32, 8, 64, 2
         p = moe_lib.init_moe(jax.random.PRNGKey(0), d, e, f, False, f)
         x = jax.random.normal(jax.random.PRNGKey(1), (8, 16, d),
@@ -105,7 +106,7 @@ class TestShardedTrainStep:
         opt = adamw_init(params)
         p_ref, _, m_ref = jax.jit(step)(params, opt, batch, jnp.asarray(0))
 
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = make_mesh((2, 4), ("data", "model"))
         with shlib.activity(mesh, {}):
             sh = param_shardings(params, mesh)
             params_s = jax.device_put(params, sh)
@@ -132,7 +133,7 @@ class TestDiloco:
         from repro.parallel import diloco
         from repro.core.hlo_analysis import parse_collectives
         from jax.sharding import NamedSharding
-        mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+        mesh = make_mesh((2, 2, 2), ("pod", "data", "model"))
         cfg = reduced_config(get_config("qwen1.5-0.5b"), d_model=32,
                              n_layers=2, vocab=128)
         params = init_params(jax.random.PRNGKey(0), cfg)
@@ -186,7 +187,7 @@ class TestElasticRestore:
         params = init_params(jax.random.PRNGKey(0), cfg)
         checkpoint.save(r"{tmp_path}", 7, params)
 
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = make_mesh((2, 4), ("data", "model"))
         with shlib.activity(mesh, {{}}):
             sh = param_shardings(params, mesh)
             restored = checkpoint.restore(r"{tmp_path}", 7, params,
@@ -211,7 +212,7 @@ class TestCompressedPsum:
         run_sub("""
         from repro.compat import shard_map
         from repro.parallel.compression import compressed_psum_tree
-        mesh = jax.make_mesh((8,), ("pod",))
+        mesh = make_mesh((8,), ("pod",))
         x = jax.random.normal(jax.random.PRNGKey(0), (8, 1, 64))
         true_mean = jnp.mean(x, 0)   # (1, 64)
 

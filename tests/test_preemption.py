@@ -21,7 +21,8 @@ def _launch(ckpt_dir: str, steps: int):
          "--n-layers", "2", "--steps", str(steps), "--batch", "2",
          "--seq", "32", "--ckpt-dir", ckpt_dir, "--ckpt-every", "5",
          "--log-every", "5"],
-        env={"PYTHONPATH": "src", "PATH": os.environ.get("PATH", ""),
+        env={"PYTHONPATH": "src", "JAX_PLATFORMS": "cpu",
+             "PATH": os.environ.get("PATH", ""),
              "HOME": os.environ.get("HOME", "/root")},
         stdout=subprocess.PIPE, stderr=subprocess.STDOUT, text=True)
 

@@ -1,0 +1,113 @@
+"""Compiles for a described TPU v5e, no chip attached.
+
+The TPU compiler refuses what interpret mode accepts: tiles that overrun
+the scoped VMEM a kernel is granted, slices not aligned to the tiling.
+These tests compile the Pallas kernels of the serving path at
+qwen1.5-0.5b's published shapes, and one whole chunked-prefill step, for
+the chip and check that the kernel is in the compiled program.  The
+topology is described inside a module fixture only (one process at a
+time may load the TPU library), and every such compile lives in this
+one file.
+"""
+
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.configs import get_config
+from repro.core import TPU_V5E
+from repro.kernels import ops
+from repro.kernels.autotune import _vmem_budget, autotune_matmul
+from repro.models import init_params
+from repro.serving import WidthVariantCompileCache
+from repro.serving.compile_cache import decode_state_struct
+
+D, F = 1024, 2816          # qwen1.5-0.5b d_model, d_ff
+KERNEL = "tpu_custom_call"
+
+
+@pytest.fixture(scope="module")
+def topo():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — no TPU compiler here
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # entries compiled for a described chip cannot be read back without
+    # one: keep the persistent compilation cache out of these compiles
+    prev = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    yield topo
+    jax.config.update("jax_enable_compilation_cache", prev)
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def _sds(shape, sharding, dtype=jnp.bfloat16):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+
+def _compiled_text(fn, *args) -> str:
+    return jax.jit(fn).lower(*args).compile().as_text()
+
+
+@pytest.mark.parametrize("m,n,k", [
+    (16, F, D), (16, D, F),            # decode, 16 slots: up/gate, down
+    (256, F, D), (512, D, F),          # prefill chunk and its tail bucket
+    (8192, F, D), (2048, D, F),        # long whole-prompt prefill
+])
+def test_matmul_autotuned_compiles(one_chip, m, n, k):
+    text = _compiled_text(
+        lambda x, w: ops.matmul(x, w, hw=TPU_V5E, force="pallas"),
+        _sds((m, k), one_chip), _sds((k, n), one_chip))
+    assert KERNEL in text
+
+
+@pytest.mark.parametrize("s", [512, 2048])
+def test_flash_attention_compiles(one_chip, s):
+    q = _sds((1, s, 16, 64), one_chip)
+    text = _compiled_text(
+        lambda q, k, v: ops.flash_attention(q, k, v, hw=TPU_V5E,
+                                            force="pallas"), q, q, q)
+    assert KERNEL in text
+
+
+def test_autotuned_bf16_cube_fits_the_compiler(one_chip):
+    """Regression: with the VMEM filter at the chip's 128 MiB the
+    autotuner chose (1024, 1024, 1024) blocks here, which the compiler
+    refuses (out of scoped VMEM).  Its choice must compile."""
+    cfg = autotune_matmul(TPU_V5E, 2048, 2048, 2048, dtype_bits=16)
+    assert cfg.vmem_bytes <= _vmem_budget(TPU_V5E)
+    bm, bn, bk = cfg.blocks
+    text = _compiled_text(
+        lambda x, w: ops.matmul(x, w, block_m=bm, block_n=bn, block_k=bk,
+                                force="pallas"),
+        _sds((2048, 2048), one_chip), _sds((2048, 2048), one_chip))
+    assert KERNEL in text
+
+
+def test_full_width_chunk_step_compiles(one_chip):
+    """The serving engine's prefill-chunk executable at the published
+    widths, as ``warm_compile`` builds it, with the MLP on the kernel."""
+    cfg = get_config("qwen1.5-0.5b")
+    place = lambda t: jax.tree.map(lambda s: _sds(s.shape, one_chip,
+                                                  s.dtype), t)
+    params = place(jax.eval_shape(
+        lambda: init_params(jax.random.PRNGKey(0), cfg)))
+    state = place(decode_state_struct(cfg, 1, 2048))
+    cache = WidthVariantCompileCache(cfg, hw=TPU_V5E)
+    toks = _sds((1, 512), one_chip, jnp.int32)
+    pos = _sds((), one_chip, jnp.int32)
+    with ops.kernel_context(force="pallas"):
+        assert cache.precompile("chunk", cache.full_key, (1, 512),
+                                (params, toks, pos, state))
+    exe = cache.executable("chunk", cache.full_key, (1, 512))
+    assert KERNEL in exe.as_text()
