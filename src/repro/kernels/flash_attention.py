@@ -114,5 +114,6 @@ def flash_attention_pallas(q: jax.Array, k: jax.Array, v: jax.Array, *,
             pltpu.VMEM((bq, dh), jnp.float32),
         ],
         interpret=interpret,
+        name="flash_attention",
     )(qr, kr, vr)
     return out.reshape(b, h, sq, dh).transpose(0, 2, 1, 3)

@@ -66,6 +66,7 @@ def matmul_pallas(x: jax.Array, w: jax.Array, *, block_m: int = 256,
         out_shape=jax.ShapeDtypeStruct((m, n), x.dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
         interpret=interpret,
+        name="matmul_tiled",
     )(x, w)
 
 

@@ -42,6 +42,7 @@ unchanged.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import time
 from collections import OrderedDict
 from typing import Any, Callable, List, Optional
@@ -80,12 +81,15 @@ class TraceCounter:
     ``jax.jit`` only runs the wrapped Python callable on a trace-cache
     miss, so incrementing inside the body counts traces exactly: AOT
     ``lower()`` calls count (they trace once, at plan time), warm
-    executable calls and jit-cache hits do not."""
+    executable calls and jit-cache hits do not.  The wrapper keeps
+    ``fn``'s name, so the executable ``jax.jit`` builds from it is named
+    after ``fn`` (``jit_decode``, ...) in HLO and in profiler traces."""
 
     def __init__(self) -> None:
         self.count = 0
 
     def wrap(self, fn: Callable) -> Callable:
+        @functools.wraps(fn)
         def counted(*args, **kwargs):
             self.count += 1
             return fn(*args, **kwargs)
@@ -153,23 +157,25 @@ class WidthVariantCompileCache:
         # The single pair of jit wrappers used for BOTH plan-time AOT
         # lowering and the serve-time traced fallback; their bodies run
         # under the kernel context so Pallas backends get autotuned
-        # tiles (inert in ref mode — numerics unchanged).
-        def prefill_fn(p, toks):
+        # tiles (inert in ref mode — numerics unchanged).  Each function
+        # is named for its kind: the executables are jit_prefill,
+        # jit_decode and jit_chunk.
+        def prefill(p, toks):
             with ops.kernel_context(hw=self.hw, cache=self.tile_cache):
                 return tfm.forward(p, cfg, tokens=toks, mode="prefill")
 
-        def decode_fn(p, t, pos, st):
+        def decode(p, t, pos, st):
             with ops.kernel_context(hw=self.hw, cache=self.tile_cache):
                 return tfm.decode_step(p, cfg, t, pos, st)
 
-        def chunk_fn(p, toks, pos, st):
+        def chunk(p, toks, pos, st):
             with ops.kernel_context(hw=self.hw, cache=self.tile_cache):
                 return tfm.prefill_chunk(p, cfg, toks, pos, st)
 
         self._jit = {
-            "prefill": jax.jit(self.tracer.wrap(prefill_fn)),
-            "decode": jax.jit(self.tracer.wrap(decode_fn)),
-            "chunk": jax.jit(self.tracer.wrap(chunk_fn)),
+            "prefill": jax.jit(self.tracer.wrap(prefill)),
+            "decode": jax.jit(self.tracer.wrap(decode)),
+            "chunk": jax.jit(self.tracer.wrap(chunk)),
         }
 
     # ------------------------------------------------------------------

@@ -44,6 +44,14 @@ from that batch demo toward a loaded server:
     Poisson/burst traffic per class on a ``VirtualClock`` so tail
     percentiles (p50/p99/p99.9 via ``chaos.TailReport``) are exactly
     reproducible from a seed.
+  * **Spans** — with ``spans`` on, every phase of :meth:`step` opens a
+    profiler span (``serving.spans``): ``engine.step`` around the body;
+    inside it ``engine.deliver``, ``engine.boundary``, ``engine.admit``,
+    ``engine.prefill`` (holding ``engine.chunk`` per chunk call and
+    ``engine.commit``), ``engine.inputs`` (host to device),
+    ``engine.decode``, ``engine.sample``, ``engine.sync`` (device to
+    host) and ``engine.retire``; ``engine.write_slot`` wherever a slot is
+    written.  Off (the default) they cost one function call each.
 
 Determinism contract: with a ``VirtualClock`` + ``batch_cost_fn`` every
 join, shed, boundary crossing, and requeue is a pure function of the
@@ -64,6 +72,7 @@ import numpy as np
 from repro.configs.base import ModelConfig
 from repro.models import transformer as tfm
 from repro.serving.engine import Request, Result, WidthPlan
+from repro.serving.spans import span
 
 
 @dataclasses.dataclass(frozen=True)
@@ -181,7 +190,8 @@ class ContinuousServeEngine:
                  prefill_bucket_min: int = 8,
                  prefill_chunk: Optional[int] = None,
                  step_token_budget: Optional[int] = None,
-                 chunk_fault_hook: Optional[Callable[[], None]] = None):
+                 chunk_fault_hook: Optional[Callable[[], None]] = None,
+                 spans=False):
         if cfg.is_encdec:
             raise ValueError("continuous batching supports decoder-only "
                              "models (no cross-attention cache rewrite)")
@@ -198,6 +208,9 @@ class ContinuousServeEngine:
         self.admission = admission
         self.degrader = degrader
         self.clock = clock
+        # profiler spans of the step's phases (serving.spans.span): off,
+        # on, or a span factory; the caller may switch it at any time
+        self.spans = spans
         self.batch_cost_fn = batch_cost_fn
         self.max_retries = max(int(max_retries), 0)
         # Plan boundaries are only *considered* every `boundary_every`
@@ -290,7 +303,6 @@ class ContinuousServeEngine:
         self._failed = 0
         self._evicted = 0
         self.steps = 0
-        self._decode_steps = 0
         self._last_boundary_fail = -(10 ** 9)
         self.plan_log: List[WidthPlan] = []
         self.swap_log: List = []
@@ -613,8 +625,9 @@ class ContinuousServeEngine:
             prompt_in[:plen] = prompt
         else:
             prompt_in = prompt
-        logits, states, _ = self._prefill(self.params_active,
-                                          prompt_in[None])
+        with span(self.spans, "engine.prefill"):
+            logits, states, _ = self._prefill(self.params_active,
+                                              prompt_in[None])
         self._write_slot(i, states, plen)
         last = logits[0, plen - 1, :self.cfg.vocab_size]
         first = int(jnp.argmax(last))
@@ -688,10 +701,10 @@ class ContinuousServeEngine:
                         continue
                 # an error from the executable itself is not a chunk
                 # fault to restart from: it propagates
-                logits, tr.chunk_state = self._chunk(
-                    self.params_active, buf[None],
-                    jnp.asarray(tr.prefill_done, jnp.int32),
-                    tr.chunk_state)
+                off = jnp.asarray(tr.prefill_done, jnp.int32)
+                with span(self.spans, "engine.chunk", tr.rid):
+                    logits, tr.chunk_state = self._chunk(
+                        self.params_active, buf[None], off, tr.chunk_state)
                 tr.prefill_done += clen
                 spent += padded
                 self.chunk_steps += 1
@@ -705,17 +718,19 @@ class ContinuousServeEngine:
         """Final chunk committed: write the checkpoint pytree into the
         shared slot cache, sample the first token from the last real
         row's logits, and switch the slot to decoding."""
-        self._write_slot(i, tr.chunk_state, plen)
-        tr.chunk_state = None
-        tr.chunk_heads = None
-        tr.chunk_eff = None
-        tr.prefill_done = 0
-        first = int(jnp.argmax(logits[0, clen - 1, :self.cfg.vocab_size]))
-        tr.generated.append(first)
-        self.pos[i] = plen
-        self._last_tok[i] = first
-        if self._done(tr):
-            self._release(i)
+        with span(self.spans, "engine.commit", tr.rid):
+            self._write_slot(i, tr.chunk_state, plen)
+            tr.chunk_state = None
+            tr.chunk_heads = None
+            tr.chunk_eff = None
+            tr.prefill_done = 0
+            first = int(jnp.argmax(
+                logits[0, clen - 1, :self.cfg.vocab_size]))
+            tr.generated.append(first)
+            self.pos[i] = plen
+            self._last_tok[i] = first
+            if self._done(tr):
+                self._release(i)
 
     def _chunk_fault(self, i: int, tr: _Tracked, e: Exception) -> None:
         """The chunk fault hook fired: free the slot and requeue the
@@ -786,18 +801,19 @@ class ContinuousServeEngine:
                                 else gv.at[i].set(lv[0].astype(gv.dtype)))
             return out
 
-        st = dict(self.states)
-        if "stack" in prefill_states:
-            stack = dict(st["stack"])
-            for key, lst in prefill_states["stack"].items():
-                stack[key] = write_group(stack[key], lst, stacked=True)
-            st["stack"] = stack
-        if "extra" in prefill_states:
-            extra = dict(st.get("extra", {}))
-            for key, lst in prefill_states["extra"].items():
-                extra[key] = write_group(extra[key], lst, stacked=False)
-            st["extra"] = extra
-        self.states = st
+        with span(self.spans, "engine.write_slot"):
+            st = dict(self.states)
+            if "stack" in prefill_states:
+                stack = dict(st["stack"])
+                for key, lst in prefill_states["stack"].items():
+                    stack[key] = write_group(stack[key], lst, stacked=True)
+                st["stack"] = stack
+            if "extra" in prefill_states:
+                extra = dict(st.get("extra", {}))
+                for key, lst in prefill_states["extra"].items():
+                    extra[key] = write_group(extra[key], lst, stacked=False)
+                st["extra"] = extra
+            self.states = st
 
     def _fresh_states(self, heads, batch: Optional[int] = None) -> dict:
         """A fresh (empty) decode pytree shaped for realized ``heads`` —
@@ -970,91 +986,100 @@ class ContinuousServeEngine:
         """One engine step: deliver arrivals, join free slots, decode one
         token for every occupied slot, account time, enforce watchdogs,
         consider a plan boundary.  Returns True while work remains."""
-        self.steps += 1
-        self._deliver()
-        if self.steps % self.boundary_every == 0:
-            self._maybe_cross_boundary()
-        prefill_tokens = self._join_waiting()
-        chunk_tokens = 0
-        if self.prefill_chunk is not None:
-            # Chunk budget: whatever the step token budget leaves after
-            # one decode token per decoding slot.  Budget-less engines
-            # run every prefilling slot one chunk per step.
-            n_decoding = sum(tr is not None and tr.chunk_state is None
-                             for tr in self._slots)
-            cbudget = None if self.step_token_budget is None \
-                else max(self.step_token_budget - n_decoding, 0)
-            chunk_tokens = self._advance_prefills(cbudget)
-        active = [i for i, tr in enumerate(self._slots)
-                  if tr is not None and tr.chunk_state is None]
-        if not active and prefill_tokens == 0 and chunk_tokens == 0:
-            if not (self._queue or self._retry) and self._pending:
-                # idle until the next arrival: fast-forward a virtual
-                # clock; a wall clock delivers immediately (open-loop
-                # arrival times in the past).
-                nxt = min(tr.arrival_t for tr in self._pending)
-                advance = getattr(self.clock, "advance", None)
-                if advance is not None and nxt > self.clock():
-                    advance(nxt - self.clock())
-                else:
-                    self._queue.extend(
-                        sorted(self._pending,
-                               key=lambda tr: (tr.arrival_t, tr.rid)))
-                    self._pending.clear()
+        sp = self.spans
+        with span(sp, "engine.step"):
+            self.steps += 1
+            with span(sp, "engine.deliver"):
+                self._deliver()
+            if self.steps % self.boundary_every == 0:
+                with span(sp, "engine.boundary"):
+                    self._maybe_cross_boundary()
+            with span(sp, "engine.admit"):
+                prefill_tokens = self._join_waiting()
+            chunk_tokens = 0
+            if self.prefill_chunk is not None:
+                # Chunk budget: whatever the step token budget leaves
+                # after one decode token per decoding slot.  Budget-less
+                # engines run every prefilling slot one chunk per step.
+                n_decoding = sum(tr is not None and tr.chunk_state is None
+                                 for tr in self._slots)
+                cbudget = None if self.step_token_budget is None \
+                    else max(self.step_token_budget - n_decoding, 0)
+                with span(sp, "engine.prefill"):
+                    chunk_tokens = self._advance_prefills(cbudget)
+            active = [i for i, tr in enumerate(self._slots)
+                      if tr is not None and tr.chunk_state is None]
+            if not active and prefill_tokens == 0 and chunk_tokens == 0:
+                if not (self._queue or self._retry) and self._pending:
+                    # idle until the next arrival: fast-forward a virtual
+                    # clock; a wall clock delivers immediately (open-loop
+                    # arrival times in the past).
+                    nxt = min(tr.arrival_t for tr in self._pending)
+                    advance = getattr(self.clock, "advance", None)
+                    if advance is not None and nxt > self.clock():
+                        advance(nxt - self.clock())
+                    else:
+                        self._queue.extend(
+                            sorted(self._pending,
+                                   key=lambda tr: (tr.arrival_t, tr.rid)))
+                        self._pending.clear()
+                    return self._outstanding()
                 return self._outstanding()
+
+            if active:
+                with span(sp, "engine.inputs"):
+                    toks = jnp.asarray(self._last_tok)
+                    posv = jnp.asarray(self.pos)
+                with span(sp, "engine.decode"):
+                    logits, self.states = self._decode(
+                        self.params_active, toks, posv, self.states)
+                logits = logits[:, :self.cfg.vocab_size]
+                cur = self._sample(logits, active)
+                with span(sp, "engine.sync"):
+                    host = np.asarray(cur)
+            with span(sp, "engine.retire"):
+                decoded = 0
+                for i in active:
+                    tr = self._slots[i]
+                    tr.generated.append(int(host[i]))
+                    self.pos[i] += 1
+                    self._last_tok[i] = int(host[i])
+                    decoded += 1
+                    if self._done(tr):
+                        self._release(i)
+
+                # time accounting: modeled (virtual clock) or measured
+                step_tokens = decoded + prefill_tokens + chunk_tokens
+                if self.batch_cost_fn is not None and step_tokens:
+                    dt = self.batch_cost_fn(self._plan_active, step_tokens)
+                    advance = getattr(self.clock, "advance", None)
+                    if advance is not None:
+                        advance(dt)
+                self._watchdog()
+                if self.admission is not None and self.degrader is not None:
+                    qb = (len(self._queue) + len(self._retry)
+                          + self.slots - 1) // self.slots
+                    self.degrader.observe(self.admission.signal(qb))
             return self._outstanding()
 
-        t0 = self.clock()
-        decoded = 0
-        if active:
-            toks = jnp.asarray(self._last_tok)
-            posv = jnp.asarray(self.pos)
-            logits, self.states = self._decode(self.params_active, toks,
-                                               posv, self.states)
-            logits = logits[:, :self.cfg.vocab_size]
-            cur = self._sample(logits, active)
-            host = np.asarray(cur)
-            for i in active:
-                tr = self._slots[i]
-                tr.generated.append(int(host[i]))
-                self.pos[i] += 1
-                self._last_tok[i] = int(host[i])
-                decoded += 1
-                if self._done(tr):
-                    self._release(i)
-            self._decode_steps += 1
-
-        # time accounting: modeled (virtual clock) or measured
-        step_tokens = decoded + prefill_tokens + chunk_tokens
-        if self.batch_cost_fn is not None and step_tokens:
-            dt = self.batch_cost_fn(self._plan_active, step_tokens)
-            advance = getattr(self.clock, "advance", None)
-            if advance is not None:
-                advance(dt)
-        self._watchdog()
-        if self.admission is not None and self.degrader is not None:
-            qb = (len(self._queue) + len(self._retry)
-                  + self.slots - 1) // self.slots
-            self.degrader.observe(self.admission.signal(qb))
-        del t0
-        return self._outstanding()
-
     def _sample(self, logits, active):
-        temps = [self._slots[i].request.temperature for i in active]
-        if not any(t > 0 for t in temps):
-            return jnp.argmax(logits, axis=-1).astype(jnp.int32)
-        temp = np.ones(self.slots, np.float32)
-        use = np.zeros(self.slots, bool)
-        for i in active:
-            t = self._slots[i].request.temperature
-            if t > 0:
-                temp[i] = max(t, 1e-6)
-                use[i] = True
-        self.rng, sub = jax.random.split(self.rng)
-        nxt = jax.random.categorical(
-            sub, logits / jnp.asarray(temp)[:, None], axis=-1)
-        greedy = jnp.argmax(logits, axis=-1)
-        return jnp.where(jnp.asarray(use), nxt, greedy).astype(jnp.int32)
+        with span(self.spans, "engine.sample"):
+            temps = [self._slots[i].request.temperature for i in active]
+            if not any(t > 0 for t in temps):
+                return jnp.argmax(logits, axis=-1).astype(jnp.int32)
+            temp = np.ones(self.slots, np.float32)
+            use = np.zeros(self.slots, bool)
+            for i in active:
+                t = self._slots[i].request.temperature
+                if t > 0:
+                    temp[i] = max(t, 1e-6)
+                    use[i] = True
+            self.rng, sub = jax.random.split(self.rng)
+            nxt = jax.random.categorical(
+                sub, logits / jnp.asarray(temp)[:, None], axis=-1)
+            greedy = jnp.argmax(logits, axis=-1)
+            return jnp.where(jnp.asarray(use), nxt,
+                             greedy).astype(jnp.int32)
 
     def _outstanding(self) -> bool:
         return (bool(self._pending) or bool(self._queue)

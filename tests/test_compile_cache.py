@@ -124,6 +124,9 @@ class TestExecutables:
         assert cache.precompile("prefill", cache.full_key, (1, 8),
                                 (params, toks))
         assert cache.stats["aot_compiles"] == 1
+        # the executable is named for its kind (HLO and profiler traces)
+        assert cache.executable("prefill", cache.full_key, (1, 8)) \
+            .as_text().startswith("HloModule jit_prefill,")
         traced_after_warm = cache.tracer.count   # lower() traced once
         out = cache.prefill(params, toks)
         out2 = cache.prefill(params, toks)
@@ -154,6 +157,8 @@ class TestExecutables:
         pos = jnp.zeros((), jnp.int32)
         assert cache.precompile("decode", cache.full_key, (b,),
                                 (params, tok, pos, struct))
+        assert cache.executable("decode", cache.full_key, (b,)) \
+            .as_text().startswith("HloModule jit_decode,")
         traced = cache.tracer.count
         states = tfm.init_decode_state(cfg, b, max_len)
         logits, new_states = cache.decode(params, tok, pos, states)

@@ -69,6 +69,7 @@ def test_matmul_autotuned_compiles(one_chip, m, n, k):
         lambda x, w: ops.matmul(x, w, hw=TPU_V5E, force="pallas"),
         _sds((m, k), one_chip), _sds((k, n), one_chip))
     assert KERNEL in text
+    assert "%matmul_tiled" in text        # the kernel's name in the HLO
 
 
 @pytest.mark.parametrize("s", [512, 2048])
@@ -78,6 +79,7 @@ def test_flash_attention_compiles(one_chip, s):
         lambda q, k, v: ops.flash_attention(q, k, v, hw=TPU_V5E,
                                             force="pallas"), q, q, q)
     assert KERNEL in text
+    assert "%flash_attention" in text
 
 
 def test_autotuned_bf16_cube_fits_the_compiler(one_chip):
@@ -110,4 +112,7 @@ def test_full_width_chunk_step_compiles(one_chip):
         assert cache.precompile("chunk", cache.full_key, (1, 512),
                                 (params, toks, pos, state))
     exe = cache.executable("chunk", cache.full_key, (1, 512))
-    assert KERNEL in exe.as_text()
+    text = exe.as_text()
+    assert KERNEL in text and "%matmul_tiled" in text
+    # the executable is named for its kind, as the trace's modules read
+    assert text.startswith("HloModule jit_chunk,")
