@@ -249,29 +249,44 @@ def local_attention_prefill(q, k, v, *, window: int, q_offset: int = 0,
 # ---------------------------------------------------------------------------
 # decode
 # ---------------------------------------------------------------------------
-def decode_attention(q, k_cache, v_cache, cache_len) -> jax.Array:
+def decode_attention(q, k_cache, v_cache, cache_len, k_new=None,
+                     v_new=None) -> jax.Array:
     """Single-token attention, replicated cache.  q: (B, H, dh).
 
     ``cache_len`` is the valid cache length — a scalar (lockstep decode)
     or a (B,) vector (ragged decode: each slot of a continuous batch at
-    its own position)."""
+    its own position).  With ``k_new``/``v_new`` (B, KV, dh) the step's
+    own row is not in the cache: the query attends over the cache's
+    first ``cache_len`` rows and that row, so the cache is only read."""
     b, h, dh = q.shape
     kv = k_cache.shape[2]
     g = h // kv
     scale = 1.0 / math.sqrt(dh)
-    s = jnp.einsum("bkgd,bskd->bkgs",
-                   q.reshape(b, kv, g, dh).astype(COMPUTE_DTYPE),
-                   k_cache.astype(COMPUTE_DTYPE),
+    qg = q.reshape(b, kv, g, dh).astype(COMPUTE_DTYPE)
+    s = jnp.einsum("bkgd,bskd->bkgs", qg, k_cache.astype(COMPUTE_DTYPE),
                    preferred_element_type=jnp.float32) * scale
     pos = jnp.arange(k_cache.shape[1])
     cache_len = jnp.asarray(cache_len)
     if cache_len.ndim == 1:                # per-slot valid lengths
         cache_len = cache_len[:, None, None, None]
     s = jnp.where(pos[None, None, None, :] < cache_len, s, NEG_INF)
-    p = jax.nn.softmax(s, axis=-1)
-    o = jnp.einsum("bkgs,bskd->bkgd", p.astype(COMPUTE_DTYPE),
+    if k_new is None:
+        p = jax.nn.softmax(s, axis=-1)
+        o = jnp.einsum("bkgs,bskd->bkgd", p.astype(COMPUTE_DTYPE),
+                       v_cache.astype(COMPUTE_DTYPE),
+                       preferred_element_type=jnp.float32)
+        return o.reshape(b, h, dh).astype(COMPUTE_DTYPE)
+    # softmax over the cache's rows and the new row, taken apart
+    s_new = jnp.einsum("bkgd,bkd->bkg", qg, k_new.astype(COMPUTE_DTYPE),
+                       preferred_element_type=jnp.float32)[..., None] * scale
+    m = jnp.maximum(jnp.max(s, axis=-1, keepdims=True), s_new)
+    e, e_new = jnp.exp(s - m), jnp.exp(s_new - m)
+    den = jnp.sum(e, axis=-1, keepdims=True) + e_new
+    o = jnp.einsum("bkgs,bskd->bkgd", (e / den).astype(COMPUTE_DTYPE),
                    v_cache.astype(COMPUTE_DTYPE),
                    preferred_element_type=jnp.float32)
+    o = o + ((e_new / den).astype(COMPUTE_DTYPE).astype(jnp.float32)
+             * v_new.astype(COMPUTE_DTYPE).astype(jnp.float32)[:, :, None])
     return o.reshape(b, h, dh).astype(COMPUTE_DTYPE)
 
 
@@ -362,13 +377,29 @@ def flash_decode_sharded(q, k_cache, v_cache, cache_len, mesh: Mesh,
     )(q, k_cache, v_cache, cache_len)
 
 
+def write_rows(cache, rows, pos):
+    """Write one new K or V row per slot into ``cache`` (..., B, S, KV, dh)
+    at sequence position ``pos``: a scalar (every slot) or a (B,) vector
+    (ragged decode).  ``rows`` is (..., B, KV, dh), with the same leading
+    axes as the cache (a layer stack).  Written as dynamic-update-slices,
+    which keep the cache's device layout: on a donated cache the write is
+    in place."""
+    lead = (0,) * (cache.ndim - 4)
+    rows = rows.astype(cache.dtype)[..., None, :, :]
+    if jnp.ndim(pos) == 0:
+        return jax.lax.dynamic_update_slice(cache, rows, lead + (0, pos, 0, 0))
+    for b in range(rows.shape[-4]):
+        cache = jax.lax.dynamic_update_slice(
+            cache, rows[..., b:b + 1, :, :, :], lead + (b, pos[b], 0, 0))
+    return cache
+
+
 def update_cache_sharded(cache, new, pos, mesh: Optional[Mesh],
                          seq_axis: str = "model"):
     """Write (B, KV, dh) `new` at sequence position `pos` of a seq-sharded
     cache (B, S, KV, dh).  Only the owning shard commits the write."""
     if mesh is None or seq_axis not in mesh.axis_names:
-        return jax.lax.dynamic_update_slice(
-            cache, new[:, None].astype(cache.dtype), (0, pos, 0, 0))
+        return write_rows(cache, new, pos)
     n_shards = mesh.shape[seq_axis]
     s_loc = cache.shape[1] // n_shards
     dp = _dp_axes(mesh)

@@ -162,9 +162,24 @@ def _default_positions(cfg: ModelConfig, b: int, s: int, offset=0):
     return pos
 
 
+def _decode_appends_rows(kind: str, mode: str, pos) -> bool:
+    """A decode layer of global attention only reads its cache and
+    returns its new K/V rows; ``apply_stack`` writes them after the layer
+    scan (``attn_lib.write_rows``).  Writing inside the scan makes XLA
+    move the cache into another device layout and back every step.  The
+    lockstep decode of a sequence-sharded cache (a ``model`` mesh axis)
+    keeps its own per-layer write."""
+    mesh = current_mesh()
+    return (mode == "decode" and kind == "attn"
+            and (jnp.ndim(pos) == 1 or mesh is None
+                 or "model" not in mesh.axis_names))
+
+
 def _self_attention(p, x, cfg: ModelConfig, kind: str, mode: str,
                     cache, positions, pos, causal: bool):
-    """Self-attention for train / prefill / decode.  Returns (y, cache)."""
+    """Self-attention for train / prefill / decode.  Returns (y, cache);
+    under ``_decode_appends_rows``, (y, new rows) with the rows (B, KV,
+    dh) in place of the cache."""
     b = x.shape[0]
     if mode == "decode":
         # Ragged decode (continuous batching): `pos` may be a (B,) vector
@@ -176,49 +191,33 @@ def _self_attention(p, x, cfg: ModelConfig, kind: str, mode: str,
         rp = positions if positions is not None else (
             _default_positions(cfg, b, 1, pos[:, None] if ragged else pos))
         q, k = _rope(cfg, q, k, rp)
-        mesh = current_mesh()
-        if ragged:
-            b_idx = jnp.arange(b)
-            if kind == "local":
-                w = cfg.window
-                slot = pos % w
+        if _decode_appends_rows(kind, mode, pos):
+            rows = {"k": k[:, 0].astype(cache["k"].dtype),
+                    "v": v[:, 0].astype(cache["v"].dtype)}
+            o = attn_lib.decode_attention(q[:, 0], cache["k"], cache["v"],
+                                          pos, rows["k"], rows["v"])
+            return attn_lib.out_proj(p, o[:, None]), rows
+        if kind == "local":
+            w = cfg.window
+            slot = pos % w
+            if ragged:
+                b_idx = jnp.arange(b)
                 kc = cache["k"].at[b_idx, slot].set(
                     k[:, 0].astype(cache["k"].dtype))
                 vc = cache["v"].at[b_idx, slot].set(
                     v[:, 0].astype(cache["v"].dtype))
-                valid = jnp.minimum(pos + 1, w)
-            else:
-                kc = cache["k"].at[b_idx, pos].set(
-                    k[:, 0].astype(cache["k"].dtype))
-                vc = cache["v"].at[b_idx, pos].set(
-                    v[:, 0].astype(cache["v"].dtype))
-                valid = pos + 1
-            o = attn_lib.decode_attention(q[:, 0], kc, vc, valid)
-            y = attn_lib.out_proj(p, o[:, None])
-            return y, {"k": kc, "v": vc}
-        if kind == "local":
-            w = cfg.window
-            slot = pos % w
-            kc = jax.lax.dynamic_update_slice(
-                cache["k"], k.astype(cache["k"].dtype), (0, slot, 0, 0))
-            vc = jax.lax.dynamic_update_slice(
-                cache["v"], v.astype(cache["v"].dtype), (0, slot, 0, 0))
-            valid = jnp.minimum(pos + 1, w)
-            o = attn_lib.decode_attention(q[:, 0], kc, vc, valid)
-        else:
-            if mesh is not None and "model" in mesh.axis_names:
-                kc = attn_lib.update_cache_sharded(cache["k"], k[:, 0], pos,
-                                                   mesh)
-                vc = attn_lib.update_cache_sharded(cache["v"], v[:, 0], pos,
-                                                   mesh)
-                o = attn_lib.flash_decode_sharded(q[:, 0], kc, vc, pos + 1,
-                                                  mesh)
             else:
                 kc = jax.lax.dynamic_update_slice(
-                    cache["k"], k.astype(cache["k"].dtype), (0, pos, 0, 0))
+                    cache["k"], k.astype(cache["k"].dtype), (0, slot, 0, 0))
                 vc = jax.lax.dynamic_update_slice(
-                    cache["v"], v.astype(cache["v"].dtype), (0, pos, 0, 0))
-                o = attn_lib.decode_attention(q[:, 0], kc, vc, pos + 1)
+                    cache["v"], v.astype(cache["v"].dtype), (0, slot, 0, 0))
+            o = attn_lib.decode_attention(q[:, 0], kc, vc,
+                                          jnp.minimum(pos + 1, w))
+        else:
+            mesh = current_mesh()
+            kc = attn_lib.update_cache_sharded(cache["k"], k[:, 0], pos, mesh)
+            vc = attn_lib.update_cache_sharded(cache["v"], v[:, 0], pos, mesh)
+            o = attn_lib.flash_decode_sharded(q[:, 0], kc, vc, pos + 1, mesh)
         y = attn_lib.out_proj(p, o[:, None])
         return y, {"k": kc, "v": vc}
 
@@ -523,6 +522,11 @@ def apply_stack(stack_p: dict, x: jax.Array, cfg: ModelConfig, *,
                 else (stack_p["stack"],)
             x, (ns_stacked, aux_stacked) = jax.lax.scan(scan_fn, x, xs)
             if mode != "train":
+                for j, (kind, _) in enumerate(unit_plan):
+                    if has_states and _decode_appends_rows(kind, mode, pos):
+                        ns, st = ns_stacked[f"u{j}"], states["stack"][f"u{j}"]
+                        for n in ("k", "v"):
+                            ns[n] = attn_lib.write_rows(st[n], ns[n], pos)
                 new_states_out["stack"] = ns_stacked
             aux_total = {k: aux_total[k] + jnp.sum(aux_stacked[k])
                          for k in aux_total}
@@ -536,6 +540,9 @@ def apply_stack(stack_p: dict, x: jax.Array, cfg: ModelConfig, *,
                 stack_p["extra"][f"x{j}"], x, cfg, kind, mlpk, mode=mode,
                 state=st, enc_out=enc_out, positions=positions, pos=pos,
                 causal=causal, moe_strategy=moe_strategy)
+            if st is not None and _decode_appends_rows(kind, mode, pos):
+                for n in ("k", "v"):
+                    ns[n] = attn_lib.write_rows(st[n], ns[n], pos)
             if mode != "train":
                 new_states_out.setdefault("extra", {})[f"x{j}"] = ns
             aux_total = {k: aux_total[k] + aux[k] for k in aux_total}

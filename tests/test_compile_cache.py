@@ -7,6 +7,7 @@ statistical.
 """
 
 import dataclasses
+import re
 
 import numpy as np
 import pytest
@@ -166,6 +167,31 @@ class TestExecutables:
         assert cache.stats["hits"] == 1
         assert logits.shape[0] == b
         jax.tree_util.tree_map(lambda a, s: None, new_states, states)
+
+    def test_decode_executable_moves_no_cache_slab(self, setup):
+        """Decode reads the stacked KV cache and writes only the new
+        rows: the compiled program builds no broadcast or copy of the
+        whole stack, and no copy or scatter of one layer's slab (the
+        per-layer slab relay it replaced had both)."""
+        cfg, params = setup
+        cache = WidthVariantCompileCache(cfg)
+        b, max_len = 4, 32
+        struct = decode_state_struct(cfg, b, max_len)
+        tok = jnp.zeros((b,), jnp.int32)
+        assert cache.precompile("decode", cache.full_key, (b,),
+                                (params, tok, tok, struct))
+        text = cache.executable("decode", cache.full_key, (b,)).as_text()
+        stack = struct["stack"]["u0"]["k"].shape
+        slabs = {stack[1:], (1,) + stack[1:]}
+        ops_by_shape = [
+            (op, tuple(int(d) for d in dims.split(",")))
+            for dims, op in re.findall(
+                r"= \w+\[([\d,]+)\]\S* ([\w-]+)\(", text)]
+        assert not [(op, sh) for op, sh in ops_by_shape
+                    if (sh == stack and op in ("broadcast", "copy"))
+                    or (sh in slabs
+                        and op in ("broadcast", "copy", "scatter"))]
+        assert ("dynamic-update-slice", stack) in ops_by_shape
 
     def test_compile_fault_absorbed_and_served_traced(self, setup):
         cfg, params = setup

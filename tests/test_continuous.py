@@ -774,6 +774,25 @@ class TestChunkedPrefill:
         led = eng.ledger()
         assert led.complete and led.failed == 1
 
+    def test_compile_cache_serves_the_same_tokens(self, setup):
+        """The serving shape of the chip benchmark (chunked joins beside
+        ragged decode, every slot busy) gives the same tokens through
+        the warm AOT executables as through the engine's own jits."""
+        cfg, params = setup
+
+        def serve(cache):
+            eng = ContinuousServeEngine(
+                params, cfg, max_len=64, batch_slots=2, prefill_chunk=4,
+                prefill_bucketing=True, compile_cache=cache)
+            if cache is not None:
+                eng.warm_compile([])
+            return eng.run(reqs_for(cfg, self.LENS, max_new=8))
+
+        cache = WidthVariantCompileCache(cfg)
+        for a, b in zip(serve(None), serve(cache)):
+            assert np.array_equal(a.tokens, b.tokens)
+        assert cache.stats["hits"] > 0 and cache.stats["misses"] == 0
+
     def test_chunk_on_ineligible_config_raises(self, setup):
         cfg, params = setup
         local_cfg = dataclasses.replace(cfg, block_pattern=("local",),

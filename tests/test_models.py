@@ -2,6 +2,8 @@
 one forward/train step on CPU asserting shapes + no NaNs, plus decode, and
 the analytic parameter count against the real initialized tree."""
 
+import dataclasses
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -12,6 +14,8 @@ from repro.models import (
     count_params_analytic, decode_step, init_decode_state, init_params,
     layer_plan, train_loss,
 )
+from repro.models import attention as attn
+from repro.models import transformer as tfm
 from repro.models.transformer import forward, padded_vocab
 
 # full XLA compiles: quick tier skips with -m "not slow"
@@ -142,3 +146,96 @@ def test_recurrent_prefill_decode_consistency():
     np.testing.assert_allclose(
         np.asarray(dec_logits, np.float32),
         np.asarray(full_logits, np.float32), rtol=5e-2, atol=5e-2)
+
+
+# ---------------------------------------------------------------------------
+# decode reads the cache and writes the new rows after the layer scan
+# ---------------------------------------------------------------------------
+def _per_slab_attention(p, x, cfg, kind, mode, cache, positions, pos,
+                        causal):
+    """Reference: a global-attention decode layer writes its new row into
+    its own cache slab, then attends over the slab."""
+    if mode != "decode" or kind != "attn":
+        return _SELF_ATTENTION(p, x, cfg, kind, mode, cache, positions, pos,
+                               causal)
+    ragged = jnp.ndim(pos) == 1
+    q, k, v = attn.qkv_proj(p, x)
+    q, k = tfm._rope(cfg, q, k, tfm._default_positions(
+        cfg, x.shape[0], 1, pos[:, None] if ragged else pos))
+
+    def put(c, new):
+        new = new.astype(c.dtype)
+        if ragged:
+            return c.at[jnp.arange(x.shape[0]), pos].set(new[:, 0])
+        return jax.lax.dynamic_update_slice(c, new, (0, pos, 0, 0))
+
+    kc, vc = put(cache["k"], k), put(cache["v"], v)
+    o = attn.decode_attention(q[:, 0], kc, vc, pos + 1)
+    return attn.out_proj(p, o[:, None]), {"k": kc, "v": vc}
+
+
+_SELF_ATTENTION = tfm._self_attention
+MAX_LEN = 32
+
+
+def _mixed_config():
+    """Global attention, RG-LRU and local attention in one unit; seven
+    layers: two scanned units and one unrolled global-attention layer."""
+    cfg = reduced_config(get_config("recurrentgemma-2b"), n_layers=7)
+    return dataclasses.replace(cfg, block_pattern=("attn", "rglru", "local"))
+
+
+@pytest.mark.parametrize("name,ragged", [
+    ("qwen1.5-0.5b", True), ("qwen1.5-0.5b", False),
+    ("mixed", True), ("mixed", False),
+    ("seamless-m4t-medium", False),
+])
+def test_decode_matches_per_slab_decode(name, ragged, monkeypatch):
+    cfg = (_mixed_config() if name == "mixed"
+           else reduced_config(get_config(name)))
+    b = 4
+    params = init_params(jax.random.PRNGKey(0), cfg)
+    st = init_decode_state(cfg, b, MAX_LEN, enc_len=8 if cfg.is_encdec
+                           else 0)
+    # a cache full of history, so every row the mask admits matters
+    keys = iter(jax.random.split(jax.random.PRNGKey(1), 64))
+    st = jax.tree.map(
+        lambda a: (jnp.full_like(a, 8) if a.dtype == jnp.int32
+                   else jax.random.normal(next(keys), a.shape,
+                                          jnp.float32).astype(a.dtype)), st)
+    tok = jnp.arange(3, 3 + b, dtype=jnp.int32)
+    pos = (jnp.asarray([0, 13, MAX_LEN - 1, 7], jnp.int32) if ragged
+           else jnp.asarray(11, jnp.int32))
+
+    logits, new = decode_step(params, cfg, tok, pos, st)
+    with monkeypatch.context() as m:
+        m.setattr(tfm, "_decode_appends_rows", lambda *a: False)
+        m.setattr(tfm, "_self_attention", _per_slab_attention)
+        ref_logits, ref = decode_step(params, cfg, tok, pos, st)
+
+    # the same states, leaf for leaf: recurrent, local and cross states
+    # come back where they were
+    assert jax.tree.structure(new) == jax.tree.structure(ref)
+    for a, r in zip(jax.tree.leaves(new), jax.tree.leaves(ref)):
+        assert a.shape == r.shape and a.dtype == r.dtype
+    np.testing.assert_allclose(np.asarray(logits, np.float32),
+                               np.asarray(ref_logits, np.float32),
+                               rtol=1e-2, atol=1e-2)
+    written = np.zeros((b, MAX_LEN), bool)
+    written[np.arange(b), np.asarray(pos)] = True
+    for path, a in jax.tree_util.tree_leaves_with_path(new):
+        a, r = (np.asarray(_leaf(t, path), np.float32) for t in (new, ref))
+        np.testing.assert_allclose(a, r, rtol=1e-2, atol=1e-2)
+        if path[-1].key in ("k", "v") and a.shape[-3] == MAX_LEN:
+            # every cache row but the new one is left as it was, exactly
+            old = np.asarray(_leaf(st, path), np.float32)
+            np.testing.assert_array_equal(a[..., ~written, :, :],
+                                          old[..., ~written, :, :])
+            np.testing.assert_array_equal(r[..., ~written, :, :],
+                                          old[..., ~written, :, :])
+
+
+def _leaf(tree, path):
+    for k in path:
+        tree = tree[k.key]
+    return tree

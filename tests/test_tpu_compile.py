@@ -3,14 +3,16 @@
 The TPU compiler refuses what interpret mode accepts: tiles that overrun
 the scoped VMEM a kernel is granted, slices not aligned to the tiling.
 These tests compile the Pallas kernels of the serving path at
-qwen1.5-0.5b's published shapes, and one whole chunked-prefill step, for
-the chip and check that the kernel is in the compiled program.  The
+qwen1.5-0.5b's published shapes, one whole chunked-prefill step and one
+whole decode step, for the chip and check that the kernel is in the
+compiled program and that decode keeps the KV cache where it lies.  The
 topology is described inside a module fixture only (one process at a
 time may load the TPU library), and every such compile lives in this
 one file.
 """
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -22,6 +24,7 @@ from repro.core import TPU_V5E
 from repro.kernels import ops
 from repro.kernels.autotune import _vmem_budget, autotune_matmul
 from repro.models import init_params
+from repro.models import transformer as tfm
 from repro.serving import WidthVariantCompileCache
 from repro.serving.compile_cache import decode_state_struct
 
@@ -116,3 +119,56 @@ def test_full_width_chunk_step_compiles(one_chip):
     assert KERNEL in text and "%matmul_tiled" in text
     # the executable is named for its kind, as the trace's modules read
     assert text.startswith("HloModule jit_chunk,")
+
+
+def _stack_ops(text, stack):
+    """(opcode, shape) of every instruction outside a fusion whose shape
+    is the stacked KV cache or one layer's slab of it: what the program
+    moves through memory, not what a fused loop computes."""
+    fused = set(re.findall(r" fusion\(.*calls=(%[\w.-]+)", text))
+    slabs = {stack, stack[1:], (1,) + stack[1:]}
+    out = []
+    for comp in re.split(r"\n(?=\S)", text):
+        if comp.split(" ", 1)[0] in fused:
+            continue
+        for dims, op in re.findall(r"= \w+\[([\d,]+)\]\S* ([\w-]+)\(",
+                                   comp):
+            shape = tuple(int(d) for d in dims.split(","))
+            if shape in slabs:
+                out.append((op, shape))
+    return out
+
+
+def test_full_width_decode_step_keeps_the_cache_layout(one_chip):
+    """The engine's decode executable at the published widths, 16 slots
+    of 2048 rows, ragged positions.  The v5e lays the cache out with the
+    sequence axis minor; decode reads each layer's slab where it lies
+    and writes the new rows with dynamic-update-slices, so no layer's
+    slab is copied into another layout and back, and nothing broadcasts
+    a fresh stack.  Undonated, the output stack is one copy of the
+    input; with the state donated there is no copy at all."""
+    cfg = get_config("qwen1.5-0.5b")
+    b, max_len = 16, 2048
+    place = lambda t: jax.tree.map(lambda s: _sds(s.shape, one_chip,
+                                                  s.dtype), t)
+    params = place(jax.eval_shape(
+        lambda: init_params(jax.random.PRNGKey(0), cfg)))
+    state = place(decode_state_struct(cfg, b, max_len))
+    toks = _sds((b,), one_chip, jnp.int32)
+    stack = state["stack"]["u0"]["k"].shape
+    cache = WidthVariantCompileCache(cfg, hw=TPU_V5E)
+    with ops.kernel_context(force="pallas"):
+        assert cache.precompile("decode", cache.full_key, (b,),
+                                (params, toks, toks, state))
+        donated = jax.jit(
+            lambda p, t, pos, st: tfm.decode_step(p, cfg, t, pos, st),
+            donate_argnums=(3,)).lower(params, toks, toks, state)
+        donated = donated.compile().as_text()
+    text = cache.executable("decode", cache.full_key, (b,)).as_text()
+    assert text.startswith("HloModule jit_decode,") and KERNEL in text
+    moved = [o for o in _stack_ops(text, stack)
+             if o[0] in ("copy", "broadcast", "scatter", "transpose")]
+    assert moved == [("copy", stack)] * 2          # the output, k and v
+    assert not [o for o in _stack_ops(donated, stack)
+                if o[0] in ("copy", "broadcast", "scatter", "transpose")]
+    assert ("dynamic-update-slice", stack) in _stack_ops(donated, stack)
