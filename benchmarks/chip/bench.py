@@ -56,10 +56,16 @@ FIELDS = {
     "d_ff": "intermediate_size", "vocab_size": "vocab_size",
     "rope_theta": "rope_theta", "tie_embeddings": "tie_word_embeddings",
     "qkv_bias": "attention_bias", "head_dim": "head_dim",
+    "window": "sliding_window", "n_experts": "num_experts",
+    "experts_per_token": "num_experts_per_tok",
+    "moe_d_ff": "moe_intermediate_size",
 }
 
 # A reduced size for the CPU rehearsal (``--rehearse``) and the tests:
-# published head size 64, a d_ff the planner can cut.
+# published head size 64, a d_ff the planner can cut.  A configuration
+# file's own ``"rehearsal"`` object is merged over ``conf`` (its
+# ``"program"`` and ``"engine"`` objects key by key), so a model with
+# experts or windows sets their reduced sizes in its own file.
 REHEARSAL = {
     "conf": {"num_hidden_layers": 2, "hidden_size": 256,
              "num_attention_heads": 4, "num_key_value_heads": 4,
@@ -110,30 +116,71 @@ def cell_spec(bench: dict, name: str) -> tuple:
     return cell, conf, load_mix(cell["traffic"])
 
 
-def program_config(conf: dict):
-    """The program's ModelConfig serving exactly what the file states."""
+def reference_module(conf: dict):
+    """The plain reference the configuration file names."""
+    return importlib.import_module(f"references.{conf['reference']}")
+
+
+def program_config(conf: dict, ref_mod=None):
+    """The program's ModelConfig serving exactly what the file states:
+    the published keys through ``FIELDS``, then the file's ``"program"``
+    object, ModelConfig fields set verbatim (a layer pattern, MoE).  The
+    reference (``ref_mod``, else the one the file names) says whether it
+    computes that model: its ``accepts(cfg, conf)`` raises if not."""
     import dataclasses as dc
     from repro.configs import get_config
     base = get_config(conf["program_arch"])
     over = {f: conf[k] for f, k in FIELDS.items() if k in conf}
+    known = {f.name for f in dc.fields(base)}
+    for f, v in conf.get("program", {}).items():
+        if f not in known:
+            raise KeyError(f"program field {f!r} is not a ModelConfig "
+                           f"field")
+        over[f] = tuple(v) if isinstance(v, list) else v
     cfg = dc.replace(base, **over)
     if dc.replace(cfg, head_dim=0).head_dim != cfg.head_dim \
             and "head_dim" not in conf:
         raise ValueError("head_dim differs from hidden_size / heads")
-    plain = (cfg.norm == "rmsnorm" and cfg.mlp_gated and not cfg.moe
-             and tuple(cfg.block_pattern) == ("attn",)
-             and cfg.rope_kind == "standard" and not cfg.parallel_block
-             and cfg.logit_softcap == 0.0 and not cfg.is_encdec)
-    if not plain:
-        raise ValueError(f"{cfg.name}: not the dense decoder the "
-                         f"reference computes")
+    (ref_mod or reference_module(conf)).accepts(cfg, conf)
     return cfg
 
 
+def check_layer_kinds(cfg, model) -> None:
+    """Raise unless the work counts (``model``, a ``work.ModelShape`` read
+    from the published keys) see each layer as the program runs it."""
+    from repro.models.transformer import layer_plan
+    plan = layer_plan(cfg)
+    attn = {"attn": "full_attention", "local": "sliding_attention"}
+    mlp = {"dense": "dense", "moe": "sparse"}
+    runs = ([attn.get(a, a) for a, _ in plan],
+            [mlp.get(f, f) for _, f in plan])
+    counted = (list(model.layer_types) or ["full_attention"] * len(plan),
+               list(model.mlp_layer_types) or ["dense"] * len(plan))
+    if runs != counted:
+        raise ValueError(f"the program runs layers {runs}; the work counts "
+                         f"read {counted}")
+    if "local" in cfg.block_pattern and cfg.window != model.sliding_window:
+        raise ValueError(f"window {cfg.window} run, {model.sliding_window} "
+                         f"counted")
+    # The program's shared expert runs at the dense width, ``cfg.d_ff``.
+    runs = (cfg.n_experts, cfg.experts_per_token, cfg.moe_d_ff,
+            cfg.d_ff if cfg.shared_expert else 0)
+    counted = (model.n_experts, model.experts_per_token, model.expert_ffn,
+               model.shared_ffn)
+    if cfg.moe and runs != counted:
+        raise ValueError(f"experts, per token, width, shared width: {runs} "
+                         f"run, {counted} counted")
+
+
 def rehearsal_conf(conf: dict) -> dict:
-    c = dict(conf)
-    c.update(REHEARSAL["conf"])
-    c["engine"] = dict(conf["engine"], **REHEARSAL["engine"])
+    own = conf.get("rehearsal", {})
+    c = dict(conf, **REHEARSAL["conf"])
+    c.update({k: v for k, v in own.items()
+              if k not in ("engine", "program")})
+    c["engine"] = {**conf["engine"], **REHEARSAL["engine"],
+                   **own.get("engine", {})}
+    if "program" in own:
+        c["program"] = {**conf.get("program", {}), **own["program"]}
     return c
 
 
@@ -252,8 +299,10 @@ class Session:
         from repro.launch.serve import build_engine
         from repro.models import init_params
 
-        self.cfg = program_config(self.conf)
+        self.ref_mod = reference_module(self.conf)
+        self.cfg = program_config(self.conf, self.ref_mod)
         self.model = ModelShape.from_conf(self.conf)
+        check_layer_kinds(self.cfg, self.model)
         e = self.conf["engine"]
         self.engine_shape = e
         scale = REHEARSAL["scale_len"] if rehearse else 1.0
@@ -270,7 +319,8 @@ class Session:
                         for r in rates}
         hw = TPU_V5E if rehearse else hardware_for_kind(
             self.device.device_kind)
-        self.weights = make_weights(self.cfg, seed, init_params)
+        self.weights = make_weights(self.cfg, seed, init_params,
+                                    self.ref_mod)
         self.engine, plans = build_engine(
             self.weights, self.cfg, hw, slots=e["slots"],
             max_len=e["max_len"], prefill_chunk=e["prefill_chunk"],
@@ -409,9 +459,6 @@ def check(sess: Session, recs: Dict[int, ReqRecord], served: dict,
     from the seed, with the longest in it.  Returns the widest gap by
     which a served token's logit lies below the reference's best (and,
     with ``control``, the same read for the fp8 control)."""
-    import importlib
-    ref_mod = importlib.import_module(
-        f"references.{sess.conf['reference']}")
     done = [r for r in recs.values() if r.ok and r.rid in served
             and len(served[r.rid])]
     out = {"sampled_requests": 0, "sampled_tokens": 0,
@@ -430,8 +477,8 @@ def check(sess: Session, recs: Dict[int, ReqRecord], served: dict,
             break
         sample.append(r)
         n_tok += len(served[r.rid])
-    ref = ref_mod.Reference(sess.conf, sess.widths[0], sess.widths[1],
-                            sess.engine_shape["max_len"])
+    ref = sess.ref_mod.Reference(sess.conf, sess.widths[0], sess.widths[1],
+                                 sess.engine_shape["max_len"])
     worst, worst_ctl = 0.0, 0.0
     for r in sample:
         g = ref.served_gaps(sess.weights, r.planned.tokens, served[r.rid])

@@ -166,17 +166,20 @@ class EngineAdapter:
     def break_path(self, fault: str) -> None:
         """Break the timed path underneath the benchmark, as a test of its
         correctness check: ``stale_state`` (the decode step returns the
-        cache it was given), ``half_batch`` (the upper half of the slots
-        gets the lower half's logits), ``altered_token`` (each sampled
-        token is changed where it is produced)."""
+        cache it was given: a copy taken before the call, which outlives
+        a decode that donates its state), ``half_batch`` (the upper half
+        of the slots gets the lower half's logits), ``altered_token``
+        (each sampled token is changed where it is produced)."""
+        import jax
         import jax.numpy as jnp
         eng = self.eng
         if fault == "stale_state":
             dec = eng._decode
 
             def stale(p, t, pos, st):
+                before = jax.tree.map(jnp.copy, st)
                 logits, _ = dec(p, t, pos, st)
-                return logits, st
+                return logits, before
             eng._decode = stale
         elif fault == "half_batch":
             dec = eng._decode

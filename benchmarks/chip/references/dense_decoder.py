@@ -40,6 +40,19 @@ EPS = 1e-6
 FP8_MAX = 448.0
 
 
+def accepts(cfg, conf: dict) -> None:
+    """Raise unless the program's ``cfg`` is the plain dense decoder this
+    module computes: RMSNorm, gated FFN, global attention in every layer,
+    standard RoPE, sequential blocks, no soft-capped logits, no encoder."""
+    plain = (cfg.norm == "rmsnorm" and cfg.mlp_gated and not cfg.moe
+             and tuple(cfg.block_pattern) == ("attn",)
+             and cfg.rope_kind == "standard" and not cfg.parallel_block
+             and cfg.logit_softcap == 0.0 and not cfg.is_encdec)
+    if not plain:
+        raise ValueError(f"{cfg.name}: not the dense decoder the "
+                         f"reference computes")
+
+
 def _layout(weights):
     dec = weights["decoder"]
     if set(dec) != {"stack"} or set(dec["stack"]) != {"u0"}:

@@ -47,3 +47,37 @@ def test_control_fails_the_limit():
     chk = res["info"]["check"]
     limit = res["checks"]["logit_gap_max"]["limit"]
     assert chk["logit_gap_max"] <= limit < chk["control_gap_max"], chk
+
+
+def test_stale_state_under_a_donated_decode(monkeypatch):
+    """A decode that donates its state (``donate_argnums=(3,)``) deletes
+    the state it was given: the stale-state fault still reads not correct,
+    and returns no deleted buffer to the engine."""
+    import jax
+    import engine_adapter
+    from repro.models import transformer as tfm
+    load = traffic.load_mix
+    monkeypatch.setattr(traffic, "load_mix",
+                        lambda name: load("decode_heavy"))
+    donated = []
+    break_path = engine_adapter.EngineAdapter.break_path
+
+    def donating_then_break(self, fault):
+        cfg = self.eng.cfg
+        step = jax.jit(lambda p, t, pos, st: tfm.decode_step(p, cfg, t, pos,
+                                                             st),
+                       donate_argnums=(3,))
+
+        def decode(p, t, pos, st):
+            out = step(p, t, pos, st)
+            donated.append(all(x.is_deleted() for x in jax.tree.leaves(st)))
+            return out
+        self.eng._decode = decode
+        break_path(self, fault)
+
+    monkeypatch.setattr(engine_adapter.EngineAdapter, "break_path",
+                        donating_then_break)
+    res = _run("stale_state")
+    assert donated and all(donated)
+    assert res["info"]["check"]["sampled_tokens"] > 0
+    assert res["correct"] is False, res["checks"]["logit_gap_max"]
